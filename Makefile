@@ -38,7 +38,7 @@ bench-smoke:
 
 # the CI perf gate: every family sweep must stay ONE compiled program
 # (--max-compiles bounds the whole run: 8 family programs + 3 telemetry
-# programs + 2 scale-out scaling workers + 5 bake-off programs — the four
+# programs + 2 scale-out scaling rows + 5 bake-off programs — the four
 # 8-policy family sweeps and the recovery pulse — + 2 correlated-failure
 # recovery programs (pair + fat-tree, telemetry riding the carry) — with
 # headroom) and every gated flow must finish (check_finished fails loudly
@@ -48,13 +48,15 @@ bench-smoke:
 # telemetry pass adds meta.telemetry recovery rows + traces/ artifacts,
 # and the exported traces must survive their own reader (trace_report
 # exits non-zero on a round-trip or Perfetto-structure failure).
-# --devices 2 forces a 2-device host mesh so the scale-out section's
-# sharded-vs-unsharded digest gate runs on a real multi-device mesh.
+# JAX_PLATFORMS=cpu with --devices 2 forces a 2-device host mesh so the
+# scale-out section's sharded-vs-unsharded digest gate runs on a real
+# multi-device mesh.
 # --audit traces every family's closed jaxpr (no compiles) and fails on
 # dtype/effect/telemetry violations or drift from the golden fingerprints
 # in tests/golden/program_fingerprints.json (meta.audit + AUDIT_report.json).
 perf-smoke:
-	python -m benchmarks.run --smoke --devices 2 --json BENCH_smoke.json \
+	JAX_PLATFORMS=cpu python -m benchmarks.run --smoke --devices 2 \
+	  --json BENCH_smoke.json \
 	  --telemetry --trace-dir traces --max-compiles 23 --audit
 	python tools/trace_report.py --summary traces/*.jsonl
 	python tools/trace_report.py --summary traces/recovery_*.jsonl \

@@ -10,17 +10,16 @@ planes x 2 cores: n = 4 distinct 4-hop paths per inter-pod flow) — at
 Two scale-out diagnostics ride along in `meta.perf`:
 
   * scaling rows — the SAME family through the flow-sharded engine
-    (`sender.shard_sweep_flows_scenarios`) at 1/2/4/8 forced host CPU
-    devices, each in a FRESH interpreter (``--scaling-worker``) because
-    ``--xla_force_host_platform_device_count`` is read once at jax
-    initialization.  Each worker reports ticks/s plus a digest of its
-    `cct` tensor, and the parent FAILS if any digest differs from the
-    unsharded sweep's: the scaling curve and the bit-identity claim are
-    checked by the same run.  On a single-core container the curve is
-    honest rather than flattering — forced host devices share one core,
-    so expect ~flat ticks/s and read the rows as a partition-overhead
-    (not speedup) measurement; real parallel gain needs
-    `devices <= physical cores` (see docs/BENCHMARKS.md).
+    (`sender.shard_sweep_flows_scenarios`) over `flow_mesh(d)` for each d
+    of the 1/2/4/8 ladder that does not exceed the devices JAX sees, all
+    in this process (a chip belongs to one process, so a child could not
+    reach it).  Each row reports ticks/s plus a digest of its `cct`
+    tensor, and the bench FAILS if any digest differs from the unsharded
+    sweep's: the scaling curve and the bit-identity claim are checked by
+    the same run.  On the CPU the devices are forced host devices
+    (`run.py --devices N` under ``JAX_PLATFORMS=cpu``); they share the
+    host's cores, so read those rows as a partition-overhead (not
+    speedup) measurement (see docs/BENCHMARKS.md).
 
   * a tick-component breakdown — standalone jitted micro-kernels of the
     three hot tick components at the family's own shapes (scatter-ring
@@ -34,10 +33,7 @@ from __future__ import annotations
 
 import argparse
 import hashlib
-import json
 import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -55,6 +51,7 @@ from benchmarks.common import (
 from repro.net.scenarios import fat_tree_scenarios, stack_scenarios
 from repro.net.sender import (
     SenderSpec,
+    flow_mesh,
     policy_sweep_params,
     shard_sweep_flows_scenarios,
     sweep_flows_scenarios,
@@ -64,12 +61,10 @@ from repro.net.transport import Policy
 POLICIES = (Policy.ECMP, Policy.WAM)
 RATE = 32
 
-_WORKER_MARK = "SCALEOUT_WORKER_JSON:"
-
 
 def _shapes(smoke: bool) -> dict:
-    """Family + scaling shapes; the worker and the parent MUST agree (the
-    bit-identity gate compares their cct digests).
+    """Family + scaling shapes, shared by the unsharded and the sharded
+    sweeps (the bit-identity gate compares their cct digests).
 
     The full pass keeps the headline 4096 coupled flows but provisions the
     fabric generously (link_capacity 32, host_rate 64, 4-packet messages)
@@ -175,63 +170,24 @@ def _tick_breakdown(topos, spec: SenderSpec) -> dict:
     }
 
 
-def _run_scaling_worker(n_devices: int, smoke: bool) -> dict:
-    """One scaling point in a FRESH interpreter: the forced-host-device
-    flag only takes effect before jax initializes, so each device count
-    needs its own process.  Returns the worker's JSON report row."""
-    env = dict(os.environ)
-    kept = [
-        p for p in env.get("XLA_FLAGS", "").split()
-        if not p.startswith("--xla_force_host_platform_device_count")
-    ]
-    env["XLA_FLAGS"] = " ".join(
-        kept + [f"--xla_force_host_platform_device_count={n_devices}"]
-    )
-    cmd = [
-        sys.executable, "-m", "benchmarks.bench_scaleout",
-        "--scaling-worker", str(n_devices),
-    ]
-    if smoke:
-        cmd.append("--smoke")
-    proc = subprocess.run(cmd, env=env, capture_output=True, text=True)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"scaleout scaling worker (devices={n_devices}) failed:\n"
-            f"{proc.stderr[-2000:]}"
-        )
-    for line in proc.stdout.splitlines():
-        if line.startswith(_WORKER_MARK):
-            return json.loads(line[len(_WORKER_MARK):])
-    raise RuntimeError(
-        f"scaleout scaling worker (devices={n_devices}) produced no "
-        f"{_WORKER_MARK} line:\n{proc.stdout[-2000:]}"
-    )
-
-
-def _scaling_worker_main(n_devices: int, smoke: bool) -> None:
-    """Entry point inside the fresh interpreter: shard the family over
-    `n_devices` forced host devices, compile once, time one run."""
-    from repro.net.sender import flow_mesh
-
-    common.ensure_host_devices(n_devices)
-    sh = _shapes(smoke)
-    _, topos, scheds, spec, sp, keys = _family(sh)
-    mesh = flow_mesh(n_devices)
+def _scaling_row(topos, scheds, spec, sp, keys, sh: dict, n_devices: int
+                 ) -> dict:
+    """One scaling point: shard the family over `flow_mesh(n_devices)`,
+    compile once, time one run."""
     compiled, compile_s = aot_compile(
         shard_sweep_flows_scenarios, topos, scheds, spec, sp,
-        sh["n_packets"], keys, horizon=sh["horizon"], mesh=mesh,
+        sh["n_packets"], keys, horizon=sh["horizon"],
+        mesh=flow_mesh(n_devices),
     )
     r, run_s = timed_call(compiled, topos, scheds, sp, sh["n_packets"], keys)
     sims = int(np.asarray(r.cct).size // sh["flows"])
-    print(_WORKER_MARK + json.dumps({
-        "devices": n_devices,
-        "compile_s": round(compile_s, 3),
-        "run_s": round(run_s, 3),
+    return {
+        "compile_s": compile_s,
+        "run_s": run_s,
         "fabric_ticks": sims * sh["horizon"],
         "path_decisions": int(np.asarray(r.sent_total).sum()),
-        "finished_frac": float(np.asarray(r.finished).mean()),
         "cct_digest": _digest(r.cct),
-    }), flush=True)
+    }
 
 
 def main() -> None:
@@ -305,12 +261,14 @@ def main() -> None:
         total_s=round(sweep_total, 3),
     )
 
-    # --- scaling rows: same family, flow-sharded, fresh interpreter per
-    # device count; digest equality against the unsharded sweep is a hard
-    # gate (a scaling curve over different numbers is worthless) ---
+    # --- scaling rows: same family, flow-sharded over every mesh size the
+    # platform has devices for; digest equality against the unsharded
+    # sweep is a hard gate (a scaling curve over different numbers is
+    # worthless) ---
+    ladder = [d for d in sh["scaling"] if d <= jax.device_count()]
     ticks_per_s = {}
-    for n_dev in sh["scaling"]:
-        row = _run_scaling_worker(n_dev, smoke)
+    for n_dev in ladder:
+        row = _scaling_row(topos, scheds, spec, sp, keys, sh, n_dev)
         if row["cct_digest"] != base_digest:
             raise RuntimeError(
                 f"scaleout scaling: sharded cct digest {row['cct_digest']} "
@@ -331,7 +289,7 @@ def main() -> None:
             f"scaleout/scaling/d{n_dev}",
             row["run_s"] * 1e6 / max(row["fabric_ticks"], 1),
             f"devices={n_dev};ticks_per_s={tps:.0f}"
-            f";speedup_vs_d1={tps / max(ticks_per_s[sh['scaling'][0]], 1e-9):.2f}"
+            f";speedup_vs_d1={tps / max(ticks_per_s[ladder[0]], 1e-9):.2f}"
             f";bit_identical=1",
             compile_count=1,
             compile_s=row["compile_s"],
@@ -340,18 +298,13 @@ def main() -> None:
     emit(
         "scaleout/scaling/curve",
         0.0,
-        ";".join(f"d{n}={ticks_per_s[n]:.0f}" for n in sh["scaling"])
+        ";".join(f"d{n}={ticks_per_s[n]:.0f}" for n in ladder)
         + f";host_cores={os.cpu_count()}",
     )
 
 
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
-    ap.add_argument("--scaling-worker", type=int, default=None, metavar="N")
     ap.add_argument("--smoke", action="store_true")
-    args = ap.parse_args()
-    if args.scaling_worker is not None:
-        _scaling_worker_main(args.scaling_worker, args.smoke)
-    else:
-        common.set_smoke(args.smoke)
-        main()
+    common.set_smoke(ap.parse_args().smoke)
+    main()

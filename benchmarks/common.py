@@ -30,7 +30,6 @@ __all__ = [
     "emit",
     "perf",
     "env_info",
-    "ensure_host_devices",
     "aot_compile",
     "compile_gate",
     "timed_call",
@@ -343,11 +342,13 @@ def telemetry_row(
 def env_info(requested_devices: int | None = None) -> Dict[str, object]:
     """The meta.env block: where this bench ran.
 
-    Captures the jax backend, visible device count (host CPU devices come
-    from ``--xla_force_host_platform_device_count``, see `run.py
-    --devices`), the flow-axis mesh shape the shard_* engines would use,
-    and the XLA flags in effect — enough to interpret a scaling row
-    without the shell that launched it.
+    Captures the platform JAX runs on, its device count and kinds (on the
+    CPU, host devices forced by `run.py --devices`), the flow mesh that
+    `--devices` asked for (the shard_* engines' mesh over the first N
+    devices; None when no mesh was asked for), and the XLA flags in
+    effect — enough to interpret a scaling row without the shell that
+    launched it.  Each `meta.perf` row carries the device count its own
+    program ran on.
     """
     import os
 
@@ -357,31 +358,12 @@ def env_info(requested_devices: int | None = None) -> Dict[str, object]:
         "device_count": len(devs),
         "device_kinds": sorted({d.device_kind for d in devs}),
         "requested_devices": requested_devices,
-        "mesh_shape": {"flows": len(devs)},
+        "mesh_shape": (
+            {"flows": requested_devices} if requested_devices else None
+        ),
         "xla_flags": os.environ.get("XLA_FLAGS", ""),
         "host_cpu_count": os.cpu_count(),
     }
-
-
-def ensure_host_devices(n: int) -> int:
-    """Assert that at least `n` jax devices are visible, else fail LOUDLY.
-
-    The force-host-device flag only works if it is in ``XLA_FLAGS`` BEFORE
-    jax initializes, so by the time this module (which imports jax) runs it
-    can only be *checked*, not set — `run.py --devices` sets it first and
-    the scaling subprocesses inherit it via the environment.  The error
-    names the exact fix instead of letting a sharded bench fall over later
-    inside `flow_mesh` with a shape error.
-    """
-    have = jax.device_count()
-    if have < n:
-        raise RuntimeError(
-            f"{n} host devices required but jax initialized with {have} — "
-            f"set XLA_FLAGS=--xla_force_host_platform_device_count={n} "
-            "before the first jax import (benchmarks/run.py --devices does "
-            "this when it is the entry point)"
-        )
-    return n
 
 
 def perf(
@@ -392,7 +374,7 @@ def perf(
     compile_s: float,
     run_s: float,
     nominal_decisions: bool = False,
-    devices: int | None = None,
+    devices: int = 1,
     breakdown: Dict[str, float] | None = None,
 ) -> None:
     """Record one meta.perf row: simulator throughput + wall split.
@@ -409,10 +391,10 @@ def perf(
     run.py surfaces these rows as `meta.perf` in the bench JSON so the perf
     trajectory is diffable run over run.
 
-    Every row is tagged with the device count it ran on (`devices`,
-    defaulting to the visible jax device count) so single- and multi-device
-    rows of the same family are never conflated; scaling drivers that run
-    workers in subprocesses pass the worker's count explicitly.  An
+    Every row is tagged with the device count its program ran on
+    (`devices`: 1 for an unsharded program, the mesh size for a
+    flow-sharded one) so single- and multi-device rows of the same family
+    are never conflated.  An
     optional `breakdown` maps tick-component names (e.g. ``scatter_ring``,
     ``path_assign``, ``rng``) to measured seconds; shares are normalized
     over the components so the row reads as "fraction of accounted
@@ -421,7 +403,7 @@ def perf(
     total = compile_s + run_s
     row: Dict[str, object] = {
         "name": name,
-        "devices": int(devices if devices is not None else jax.device_count()),
+        "devices": int(devices),
         "fabric_ticks": int(fabric_ticks),
         "path_decisions": int(path_decisions),
         "path_decisions_nominal": bool(nominal_decisions),

@@ -5,27 +5,27 @@ Flags:
   --smoke       fast small-shape pass (CI sanity, not paper-sized tables)
   --json PATH   also write results as a BENCH_*.json-compatible dict
   --only NAME   run a single section (substring match)
-  --devices N   run on N forced host CPU devices (shard_map scale-out)
+  --devices N   flow mesh over the first N devices (shard_map scale-out)
 
-`--devices` works by exporting ``--xla_force_host_platform_device_count``
-into XLA_FLAGS, which jax reads exactly once at initialization — so this
-module must stay import-light: nothing that (transitively) imports jax may
-run before `main` has handled the flag.  `benchmarks.common` is therefore
-imported inside `main`, after the environment is set.
+On the CPU (``JAX_PLATFORMS=cpu``) `--devices` works by exporting
+``--xla_force_host_platform_device_count`` into XLA_FLAGS, which XLA reads
+once, when JAX initializes — so this module must stay import-light: nothing
+that (transitively) imports jax may run before `main` has handled the flag.
+`benchmarks.common` is therefore imported inside `main`, after the
+environment is set.  A section that cannot be imported fails the run.
 """
 from __future__ import annotations
 
 import argparse
 import importlib
 import json
-import os
 import platform
 import sys
 import time
 
-# (section, module) — modules import lazily and defensively: a section whose
-# dependencies are absent (e.g. repro.dist in the seed image) is reported
-# and skipped instead of killing the whole run.
+from repro.launch.devices import request_devices, setup_compile_cache
+
+# (section, module) — modules import lazily, after `--devices` is handled
 SECTION_MODULES = [
     ("sec9_deviation_bounds", "bench_deviation"),
     ("sec4_worked_example", "bench_example_discrepancy"),
@@ -38,54 +38,17 @@ SECTION_MODULES = [
     ("policy_bakeoff", "bench_bakeoff"),
     ("recovery_dynamics", "bench_recovery"),
     ("spray_throughput", "bench_spray_throughput"),
-    ("sprayed_collective_tpu", "bench_sprayed_collective"),
     ("fountain_transport", "bench_fountain"),
     ("arch_ettr_crosslayer", "bench_arch_ettr"),
-    ("roofline_table", "bench_roofline"),
 ]
 
 
 def _load_sections(only=None):
-    sections = []
-    for name, mod in SECTION_MODULES:
-        if only is not None and only not in name:
-            continue
-        try:
-            sections.append(
-                (name, importlib.import_module(f"benchmarks.{mod}").main)
-            )
-        except ImportError as e:
-            print(f"# skipping {name}: {e}", file=sys.stderr)
-    return sections
-
-
-def _force_host_devices(n: int) -> None:
-    """Export the forced-host-device flag BEFORE jax initializes.
-
-    jax reads XLA_FLAGS exactly once, at first import — if some earlier
-    import already pulled jax in, quietly editing the environment here
-    would leave the run on the wrong device count, so that case fails
-    loudly instead (unless jax already sees enough devices, e.g. the
-    caller exported the flag before launching python).
-    """
-    flag = f"--xla_force_host_platform_device_count={n}"
-    if "jax" in sys.modules:
-        import jax
-
-        if jax.device_count() < n:
-            raise SystemExit(
-                f"--devices {n}: jax already initialized with "
-                f"{jax.device_count()} device(s); XLA_FLAGS must be set "
-                f"before the first jax import — launch via benchmarks/run.py "
-                f"directly or export XLA_FLAGS='{flag}' in the shell"
-            )
-        return
-    prev = os.environ.get("XLA_FLAGS", "")
-    kept = [
-        p for p in prev.split()
-        if not p.startswith("--xla_force_host_platform_device_count")
+    return [
+        (name, importlib.import_module(f"benchmarks.{mod}").main)
+        for name, mod in SECTION_MODULES
+        if only is None or only in name
     ]
-    os.environ["XLA_FLAGS"] = " ".join(kept + [flag])
 
 
 def main(argv=None) -> None:
@@ -95,10 +58,12 @@ def main(argv=None) -> None:
     ap.add_argument("--only", metavar="NAME", help="run sections matching NAME")
     ap.add_argument(
         "--devices", type=int, metavar="N", default=None,
-        help="force N host CPU devices (XLA_FLAGS="
+        help="make N devices of the platform JAX runs on available to the "
+        "shard_map scale-out benches (flow meshes over the first N). On the "
+        "CPU (JAX_PLATFORMS=cpu) N host devices are forced (XLA_FLAGS="
         "--xla_force_host_platform_device_count=N, set before jax "
-        "initializes) — the shard_map scale-out benches and the sharded "
-        "sweep engines see an N-device flow mesh",
+        "initializes); on an accelerator N may not exceed the devices "
+        "present",
     )
     ap.add_argument(
         "--telemetry", action="store_true",
@@ -127,15 +92,15 @@ def main(argv=None) -> None:
     )
     args = ap.parse_args(argv)
     if args.devices is not None:
-        if args.devices < 1:
-            raise SystemExit(f"--devices {args.devices}: need >= 1")
-        _force_host_devices(args.devices)
+        request_devices(args.devices)
 
     # deferred so --devices lands in XLA_FLAGS before jax initializes
     from benchmarks import common
+    from repro.net.sender import flow_mesh
 
     if args.devices is not None:
-        common.ensure_host_devices(args.devices)
+        flow_mesh(args.devices)  # fail now if the platform has too few
+    print(f"# compile cache: {setup_compile_cache()}", file=sys.stderr)
     common.set_smoke(args.smoke)
     common.set_telemetry(args.telemetry, args.trace_dir)
 
@@ -200,8 +165,9 @@ def main(argv=None) -> None:
                 "python": platform.python_version(),
                 "platform": platform.platform(),
                 # execution environment: backend, device count (forced host
-                # devices under --devices), flow-mesh shape and XLA flags —
-                # scaling rows in meta.perf are uninterpretable without it
+                # devices under --devices on the CPU), the requested flow
+                # mesh and XLA flags — scaling rows in meta.perf are
+                # uninterpretable without it
                 "env": common.env_info(requested_devices=args.devices),
                 # sweep-speed visibility: every row that reported compile
                 # accounting, plus totals — a compile-count regression (e.g.

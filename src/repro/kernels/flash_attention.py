@@ -100,7 +100,7 @@ def flash_attention_pallas(
     q_offset: int = 0,
     block_q: int = 512,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     B, H, Sq, D = q.shape
     KVH, Sk = k.shape[1], k.shape[2]

@@ -83,7 +83,7 @@ def flash_decode_pallas(
     *,
     scale: float | None = None,
     block_s: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """Returns (o, m, l): o f32[B, H, D] un-normalized, m/l f32[B, H]."""
     B, H, D = q.shape

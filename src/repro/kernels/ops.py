@@ -1,13 +1,14 @@
 """Public jit'd wrappers for the Pallas kernels, with oracle fallback.
 
 `backend` selection:
-  * "pallas"    — pl.pallas_call targeting TPU (interpret=True off-TPU, which
-                  executes the kernel body on CPU for validation).
+  * "pallas"    — pl.pallas_call compiled for the TPU.  With
+                  `interpret=True` the kernel body runs through the Pallas
+                  interpreter instead, which is how the tests check the
+                  kernels off the TPU; nothing turns it on but the caller.
   * "reference" — the pure-jnp oracle from repro.kernels.ref.
 
-The default is platform-aware: real Pallas on TPU, reference elsewhere (the
-dry-run and CPU smoke tests must produce clean XLA HLO).  Tests force
-backend="pallas" with interpret=True to validate the kernels themselves.
+The default backend is platform-aware: real Pallas on TPU, reference
+elsewhere (the dry-run and CPU smoke tests must produce clean XLA HLO).
 """
 from __future__ import annotations
 
@@ -41,19 +42,15 @@ def default_backend() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "chunked"
 
 
-def _resolve(backend: Backend) -> tuple[str, bool]:
-    """-> (backend, interpret)"""
-    if backend == "auto":
-        backend = default_backend()
-    interpret = jax.default_backend() != "tpu"
-    return backend, interpret
+def _resolve(backend: Backend) -> str:
+    return default_backend() if backend == "auto" else backend
 
 
 def spray_select(
-    counters, c, sa, sb, *, ell: int, method: int, backend: Backend = "auto"
+    counters, c, sa, sb, *, ell: int, method: int, backend: Backend = "auto",
+    interpret: bool = False,
 ):
-    backend, interpret = _resolve(backend)
-    if backend == "pallas":
+    if _resolve(backend) == "pallas":
         return spray_select_pallas(
             counters, c, sa, sb, ell=ell, method=method, interpret=interpret
         )
@@ -62,9 +59,11 @@ def spray_select(
     )(counters, c, sa, sb)
 
 
-def lt_encode(payload, neighbors, valid, *, backend: Backend = "auto"):
-    backend, interpret = _resolve(backend)
-    if backend == "pallas":
+def lt_encode(
+    payload, neighbors, valid, *, backend: Backend = "auto",
+    interpret: bool = False,
+):
+    if _resolve(backend) == "pallas":
         return lt_encode_pallas(payload, neighbors, valid, interpret=interpret)
     return jax.jit(_ref.lt_encode_ref)(payload, neighbors, valid)
 
@@ -72,8 +71,9 @@ def lt_encode(payload, neighbors, valid, *, backend: Backend = "auto"):
 def flash_attention(
     q, k, v, *, causal=True, window=None, scale=None, q_offset=0,
     backend: Backend = "auto", block_q: int = 512, block_k: int = 512,
+    interpret: bool = False,
 ):
-    backend, interpret = _resolve(backend)
+    backend = _resolve(backend)
     if backend == "pallas":
         return flash_attention_pallas(
             q, k, v, causal=causal, window=window, scale=scale,
@@ -92,10 +92,9 @@ def flash_attention(
 
 def flash_decode(
     q, k, v, kv_len, *, scale=None, backend: Backend = "auto",
-    block_s: int = 512, return_lse: bool = False,
+    block_s: int = 512, return_lse: bool = False, interpret: bool = False,
 ):
-    backend, interpret = _resolve(backend)
-    if backend == "pallas":
+    if _resolve(backend) == "pallas":
         o, m, l = flash_decode_pallas(
             q, k, v, kv_len, scale=scale, block_s=block_s,
             interpret=interpret,

@@ -16,18 +16,23 @@ adding policies or jobs does not add XLA programs.
         --archs qwen3-8b,qwen3-8b --scenario staggered_start \
         --policies WAM,ECMP --draws 4 --json out.json
 
-``--devices N`` forces N host CPU devices and runs the sweep through the
-flow-sharded engine (`cluster.shard_sweep_cluster_rounds`) — bit-identical
-metrics, a scale-out execution knob, not a model change.  The jax imports
-live inside `main` because the flag must land in XLA_FLAGS before jax
-initializes (see `repro.launch.devices`).
+``--devices N`` runs the sweep through the flow-sharded engine
+(`cluster.shard_sweep_cluster_rounds`) on a flow mesh over the first N
+devices of the platform JAX runs on — bit-identical results, so it is a
+scale-out execution knob, not a model change.  On the CPU (``JAX_PLATFORMS=cpu``)
+the N host devices are forced before JAX initializes, which is why the
+jax imports below live inside `main` (see `repro.launch.devices`).
+Compiled programs persist in JAX's compilation cache
+(`repro.launch.devices.setup_compile_cache`).
 """
 from __future__ import annotations
 
 import argparse
 import json
 
-from repro.launch.devices import add_devices_arg, force_host_devices
+from repro.launch.devices import (
+    add_devices_arg, request_devices, setup_compile_cache,
+)
 
 
 def main(argv=None) -> None:
@@ -52,11 +57,13 @@ def main(argv=None) -> None:
     add_devices_arg(ap)
     args = ap.parse_args(argv)
     if args.devices is not None:
-        force_host_devices(args.devices)
+        request_devices(args.devices)
 
     # post---devices imports: nothing above may initialize jax
     import jax
     import numpy as np
+
+    setup_compile_cache()
 
     from repro.net.cluster import sweep_cluster
     from repro.net.jobs import compile_job
@@ -74,7 +81,8 @@ def main(argv=None) -> None:
         from repro.net.sender import flow_mesh
 
         mesh = flow_mesh(args.devices)
-        print(f"devices: {args.devices} host CPU devices "
+        print(f"devices: flow mesh over {args.devices} "
+              f"{jax.default_backend()} device(s) "
               f"(flow-sharded sweep, bit-identical to unsharded)")
 
     policies = [Policy[p.strip()] for p in args.policies.split(",")]

@@ -13,21 +13,26 @@ numbers.  The policy grid rides the one-compile sweep
     PYTHONPATH=src python -m repro.launch.jobsim --arch xlstm-350m \
         --scenario pfc_storm --policies WAM,ECMP --draws 4 --json out.json
 
-``--devices N`` forces N host CPU devices and runs the sweep through the
-flow-sharded engine (`jobs.shard_sweep_job_steps`) — bit-identical ETTR,
-so it is a scale-out execution knob, not a model change.  The jax imports
-below live inside `main` because the flag must land in XLA_FLAGS before
-jax initializes (see `repro.launch.devices`).
+``--devices N`` runs the sweep through the flow-sharded engine
+(`jobs.shard_sweep_job_steps`) on a flow mesh over the first N devices of
+the platform JAX runs on — bit-identical results, so it is a scale-out
+execution knob, not a model change.  On the CPU (``JAX_PLATFORMS=cpu``)
+the N host devices are forced before JAX initializes, which is why the
+jax imports below live inside `main` (see `repro.launch.devices`).
+Compiled programs persist in JAX's compilation cache
+(`repro.launch.devices.setup_compile_cache`).
 """
 from __future__ import annotations
 
 import argparse
 import json
 
-from repro.launch.devices import add_devices_arg, force_host_devices
+from repro.launch.devices import (
+    add_devices_arg, request_devices, setup_compile_cache,
+)
 
 
-def main(argv=None) -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="qwen3-8b")
     ap.add_argument("--scenario", default="link_flap")
@@ -43,20 +48,53 @@ def main(argv=None) -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--json", metavar="PATH", help="also dump results as JSON")
     add_devices_arg(ap)
+    return ap
+
+
+def job_sweep(args: argparse.Namespace, mesh=None):
+    """Compile `args.arch` into its collective schedule and run the policy
+    sweep under `args.scenario`: returns ``(job, policies, out)``, `out` as
+    `jobs.sweep_job` returns it (flow-sharded over `mesh` when given)."""
+    import jax
+
+    from repro.net.jobs import compile_job, sweep_job
+    from repro.net.scenarios import job_scenarios
+    from repro.net.sender import SenderSpec, sender_params, stack_params
+    from repro.net.transport import Policy
+
+    policies = [Policy[p.strip()] for p in args.policies.split(",")]
+    job = compile_job(
+        args.arch, workers=args.workers, tp=args.tp,
+        iterations=args.iterations, rate=args.rate,
+        max_shard=args.max_shard,
+    )
+    scens = job_scenarios(
+        workers=args.workers, horizon=max(args.horizon, 2048)
+    )
+    topo, sched = scens[args.scenario]
+    spec = SenderSpec(rate_cap=args.rate)
+    sp = stack_params([sender_params(p, rate=args.rate) for p in policies])
+    keys = jax.random.split(jax.random.PRNGKey(args.seed), args.draws)
+    out = sweep_job(
+        topo, sched, spec, sp, [job], keys, horizon=args.horizon, mesh=mesh
+    )
+    return job, policies, out
+
+
+def main(argv=None) -> None:
+    ap = build_parser()
     args = ap.parse_args(argv)
     if args.devices is not None:
-        force_host_devices(args.devices)
+        request_devices(args.devices)
 
     # post---devices imports: nothing above may initialize jax
     import jax
     import numpy as np
 
-    from repro.net.jobs import (
-        compile_job, step_table, sweep_job, total_packets,
-    )
-    from repro.net.scenarios import JOB_SCENARIO_NAMES, job_scenarios
-    from repro.net.sender import SenderSpec, sender_params, stack_params
-    from repro.net.transport import Policy
+    setup_compile_cache()
+
+    from repro.net.jobs import step_table, total_packets
+    from repro.net.scenarios import JOB_SCENARIO_NAMES
 
     if args.scenario not in JOB_SCENARIO_NAMES:
         ap.error(
@@ -67,15 +105,11 @@ def main(argv=None) -> None:
         from repro.net.sender import flow_mesh
 
         mesh = flow_mesh(args.devices)
-        print(f"devices: {args.devices} host CPU devices "
+        print(f"devices: flow mesh over {args.devices} "
+              f"{jax.default_backend()} device(s) "
               f"(flow-sharded sweep, bit-identical to unsharded)")
 
-    policies = [Policy[p.strip()] for p in args.policies.split(",")]
-    job = compile_job(
-        args.arch, workers=args.workers, tp=args.tp,
-        iterations=args.iterations, rate=args.rate,
-        max_shard=args.max_shard,
-    )
+    job, policies, out = job_sweep(args, mesh)
     shard, _, offsets = step_table(job)
     print(f"job {job.arch}: DP={job.workers} TP={args.tp} "
           f"iterations={job.iterations}")
@@ -88,17 +122,6 @@ def main(argv=None) -> None:
     print(f"  total {total_packets(job)} packets over "
           f"{job.total_steps} ring steps; planned span "
           f"{int(offsets[-1])}+ ticks")
-
-    scens = job_scenarios(
-        workers=args.workers, horizon=max(args.horizon, 2048)
-    )
-    topo, sched = scens[args.scenario]
-    spec = SenderSpec(rate_cap=args.rate)
-    sp = stack_params([sender_params(p, rate=args.rate) for p in policies])
-    keys = jax.random.split(jax.random.PRNGKey(args.seed), args.draws)
-    out = sweep_job(
-        topo, sched, spec, sp, [job], keys, horizon=args.horizon, mesh=mesh
-    )
 
     print(f"\nscenario {args.scenario} ({args.draws} draws, "
           f"horizon {args.horizon}):")

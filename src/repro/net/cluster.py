@@ -575,7 +575,7 @@ def shard_run_cluster_rounds(
 ) -> Dict[str, jax.Array]:
     """`run_cluster_rounds` with the cluster's flow axis sharded over `mesh`
     (see `sender.flow_mesh`): bit-identical ``{"cct": [..., R, F], ...}``,
-    each round's coupled simulation split across host devices (flow counts
+    each round's coupled simulation split across the mesh devices (flow counts
     that don't divide the device count are padded with silent flows and
     sliced back off).  Telemetry is not supported on this path."""
     from jax.experimental.shard_map import shard_map

@@ -115,9 +115,14 @@ def fabric_tick(
     delay = jnp.minimum(delay, params.ring_len - 1)
     slot = (t + 1 + delay) % params.ring_len  # [..., n]
     arrive_ring = state.arrive_ring
-    # scatter-add each path's served packets into its landing slot
+    # scatter-add each path's served packets into its landing slot; HIGHEST
+    # keeps the f32 operands exact on the TPU, whose default matmul
+    # precision would round `served` to bf16
     ring_idx = jax.nn.one_hot(slot, params.ring_len, dtype=served.dtype)
-    arrive_ring = arrive_ring + jnp.einsum("...n,...nr->...r", served, ring_idx)
+    arrive_ring = arrive_ring + jnp.einsum(
+        "...n,...nr->...r", served, ring_idx,
+        precision=jax.lax.Precision.HIGHEST,
+    )
 
     # --- deliveries landing this tick ---
     cur = t % params.ring_len

@@ -481,7 +481,7 @@ def shard_run_job_steps(
 ) -> Tuple[jax.Array, jax.Array]:
     """`run_job_steps` with the W ring flows sharded over `mesh` (see
     `sender.flow_mesh`): bit-identical ``(cct[S], finished[S])``, the
-    per-step coupled simulation split across host devices."""
+    per-step coupled simulation split across the mesh devices."""
     from jax.experimental.shard_map import shard_map
 
     topo_g, sizes, local_run, n_shards = _shard_job_setup(

@@ -965,7 +965,7 @@ def _sweep_flows_traced(
 
 
 # --------------------------------------------------------------------------
-# Flow-sharded execution: shard_map over multiple host devices.
+# Flow-sharded execution: shard_map over multiple devices.
 #
 # The flow axis is split into contiguous blocks, one per device; every
 # INPUT is replicated (the topology, schedule, params and keys are small —
@@ -974,10 +974,10 @@ def _sweep_flows_traced(
 #
 #   * every per-flow PRNG stream (the per-tick `split(ka, F)` fan-out, the
 #     ECMP hash draw, the fidx-derived spray seeds) is derived at the REAL
-#     flow count F and then padded/sliced — threefry key streams are NOT
-#     split-count-prefix-stable (`split(k, F_pad)[:F] != split(k, F)`), so
-#     deriving at the padded count would silently change every flow's
-#     randomness;
+#     flow count F and then padded/sliced — only the partitionable
+#     threefry (JAX 0.9's default) is split-count-prefix-stable; under the
+#     other mode `split(k, F_pad)[:F] != split(k, F)`, so deriving at the
+#     padded count would silently change every flow's randomness;
 #   * the two per-link segment-sums inside `shared_fabric_tick` all_gather
 #     the flow axis first (`axis_name=`/`route_global=`), reproducing the
 #     unsharded scatter-add in the exact same float order — so the global
@@ -997,21 +997,29 @@ FLOW_AXIS = "flows"
 
 
 def flow_mesh(n_devices: int | None = None):
-    """A 1-D device mesh over the `FLOW_AXIS` used by the shard_* engines.
+    """A 1-D device mesh over the `FLOW_AXIS` used by the shard_* engines:
+    the first `n_devices` devices of the platform JAX runs on (default:
+    every device).
 
-    Defaults to every visible device.  Multiple host CPU devices come from
-    `XLA_FLAGS=--xla_force_host_platform_device_count=N`, which must be in
-    the environment BEFORE jax initializes — see `benchmarks/run.py
-    --devices` and `benchmarks.common.ensure_host_devices`.
+    On a TPU host these are the chips present, and asking for more is an
+    error.  On the CPU they are host devices, which exist only if
+    ``--xla_force_host_platform_device_count=N`` was in ``XLA_FLAGS`` BEFORE
+    jax initialized — the entry points' ``--devices N`` arranges that under
+    ``JAX_PLATFORMS=cpu`` (see `repro.launch.devices`).
     """
     devs = jax.devices()
     if n_devices is not None:
         if n_devices > len(devs):
+            platform = devs[0].platform
+            hint = (
+                f" — on the CPU, pass --devices {n_devices} to the entry "
+                "point under JAX_PLATFORMS=cpu, which forces that many host "
+                "devices before jax initializes"
+                if platform == "cpu" else ""
+            )
             raise ValueError(
-                f"flow_mesh: {n_devices} devices requested but only "
-                f"{len(devs)} visible — set XLA_FLAGS="
-                f"--xla_force_host_platform_device_count={n_devices} "
-                "before jax initializes (benchmarks/run.py --devices)"
+                f"flow_mesh: {n_devices} devices requested but the "
+                f"{platform} platform has only {len(devs)}{hint}"
             )
         devs = devs[:n_devices]
     return jax.sharding.Mesh(np.asarray(devs), (FLOW_AXIS,))

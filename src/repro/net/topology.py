@@ -462,12 +462,14 @@ def scatter_delivery(
     which materialized an [F, n, ring_len] tensor every tick.  The per-slot
     contributions are accumulated into a zero buffer first and added to the
     ring in one op, preserving the einsum's float association
-    (ring + sum_n(contribs)) bit for bit.
+    (ring + sum_n(contribs)) bit for bit.  The barrier keeps XLA from
+    folding that add into the scatter, which would add each contribution
+    to the ring one at a time.
     """
     F = arrive_ring.shape[0]
     fidx = jnp.broadcast_to(jnp.arange(F)[:, None], slot.shape)
     deposits = jnp.zeros_like(arrive_ring).at[fidx, slot].add(exiting)
-    return arrive_ring + deposits
+    return arrive_ring + jax.lax.optimization_barrier(deposits)
 
 
 def shared_fabric_tick(

@@ -10,12 +10,19 @@ Two pinned files live next to this script:
     trace is a semantic change, not a refactor.  The file is NEVER
     rewritten by default — even value-identical arrays would change the
     file bytes (zip member timestamps), and the whole point of the file is
-    that it predates the refactors it gates.
+    that it predates the refactors it gates.  It was re-pinned once, when
+    the installed JAX moved to 0.9.0, whose default threefry is
+    partitionable (`jax_threefry_partitionable=True`).
   * ``transport_policies.npz`` — the same trace schema for the
     state-bearing bake-off policies (PRIME / STRACK / CC_COUPLED), coded +
     ARQ, plus a coupled-flows case per policy.  Pinned when the policies
     landed; regenerating it is a semantic change to THOSE policies only
     and must leave transport_seed.npz untouched.
+
+Both files record the JAX version and PRNG mode they were pinned under
+(``meta/jax_version``, ``meta/threefry_partitionable``); the tests assert
+that the running JAX matches them, since the traces are bit-identity pins
+of one JAX version's random bits.
 
 Only rerun deliberately — never to make a red test green:
 
@@ -44,6 +51,7 @@ from repro.net.topology import leaf_spine, null_schedule
 OUT = os.path.join(os.path.dirname(__file__), "transport_seed.npz")
 OUT_POLICIES = os.path.join(os.path.dirname(__file__), "transport_policies.npz")
 FIELDS = ("cct", "sent_total", "dropped_total", "final_b", "received")
+META_KEYS = ("meta/jax_version", "meta/threefry_partitionable")
 
 NEW_POLICIES = (Policy.PRIME, Policy.STRACK, Policy.CC_COUPLED)
 
@@ -116,6 +124,16 @@ def golden_policy_flows_cases():
     ]
 
 
+def pin_meta() -> dict:
+    """The JAX version and PRNG mode the traces are pinned under."""
+    return {
+        "meta/jax_version": np.asarray(jax.__version__),
+        "meta/threefry_partitionable": np.asarray(
+            bool(jax.config.jax_threefry_partitionable)
+        ),
+    }
+
+
 def _render_message(blobs, cases):
     for name, params, cfg, n_packets, seed, horizon in cases:
         r = simulate_message(
@@ -130,7 +148,7 @@ def main(argv=None) -> None:
     argv = sys.argv[1:] if argv is None else argv
     write_seed = "--seed" in argv
 
-    blobs = {}
+    blobs = pin_meta()
     _render_message(blobs, golden_policy_cases())
     for name, topo, sched, cfg, n_packets, seed, horizon in golden_policy_flows_cases():
         r = simulate_flows(
@@ -144,7 +162,7 @@ def main(argv=None) -> None:
 
     if not write_seed:
         return
-    blobs = {}
+    blobs = pin_meta()
     _render_message(blobs, golden_cases())
     topo, sched, cfg, n_packets, seed, horizon = golden_flows_case()
     r = simulate_flows(topo, sched, cfg, n_packets, jax.random.PRNGKey(seed), horizon)

@@ -304,7 +304,7 @@ else:
 
 
 # ---------------------------------------------------------------------------
-# spray_select: padded final block + interpret auto-detect
+# spray_select: padded final block + small and empty batches
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("method", [0, 1, 2])
 @pytest.mark.parametrize("B", [1, 5, 1000, 1537, 2051])
@@ -315,7 +315,8 @@ def test_spray_select_non_multiple_batches(method, B):
     prof = quantize_profile(RNG.random(n) + 0.01, ell)
     counters = jnp.asarray(RNG.integers(0, 2**31, B, dtype=np.uint32))
     got = ops.spray_select(
-        counters, prof.c, 17, 9, ell=ell, method=method, backend="pallas"
+        counters, prof.c, 17, 9, ell=ell, method=method, backend="pallas",
+        interpret=True,
     )
     want = ref.spray_select_ref(
         counters, prof.c, 17, 9, ell=ell, method=method
@@ -331,7 +332,7 @@ def test_spray_select_batch_smaller_than_block():
     prof = quantize_profile(np.arange(1, n + 1, dtype=float), ell)
     counters = jnp.arange(37, dtype=jnp.uint32)
     got = spray_select_pallas(
-        counters, prof.c, 5, 3, ell=ell, method=1, block=256
+        counters, prof.c, 5, 3, ell=ell, method=1, block=256, interpret=True
     )
     want = ref.spray_select_ref(counters, prof.c, 5, 3, ell=ell, method=1)
     assert np.array_equal(np.asarray(got), np.asarray(want))
@@ -343,7 +344,7 @@ def test_spray_select_rejects_empty_batch():
     with pytest.raises(ValueError, match="empty"):
         spray_select_pallas(
             jnp.zeros((0,), jnp.uint32), jnp.asarray([1, 2], jnp.int32),
-            0, 1, ell=4, method=0,
+            0, 1, ell=4, method=0, interpret=True,
         )
 
 

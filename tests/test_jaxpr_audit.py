@@ -49,12 +49,10 @@ def test_golden_covers_every_family():
 
 
 def test_f64_program_fails_audit():
-    from jax.experimental import enable_x64
-
     def program(x):
         return x.astype(jnp.float64) * 2.0
 
-    with enable_x64():
+    with jax.enable_x64(True):
         result = jaxpr_audit.audit_program(
             "f64", program, (jnp.ones((4,), jnp.float32),)
         )

@@ -1,6 +1,7 @@
 """Pallas kernel sweeps: shapes x dtypes vs the pure-jnp oracles.
 
-All kernels run in interpret mode on CPU (the body executes in Python);
+All kernels run in interpret mode on CPU (`interpret=True`, the body
+executes in Python);
 integer kernels must match EXACTLY, float kernels to f32 accumulation tol.
 """
 import numpy as np
@@ -27,7 +28,7 @@ def test_spray_select_sweep(method, ell, n):
     )
     got = ops.spray_select(
         counters, prof.c, 7 % (1 << ell), 9, ell=ell, method=method,
-        backend="pallas",
+        backend="pallas", interpret=True,
     )
     want = ref.spray_select_ref(
         counters, prof.c, 7 % (1 << ell), 9, ell=ell, method=method
@@ -46,7 +47,7 @@ def test_spray_select_property(ell, n, sa):
     counters = jnp.arange(1024, dtype=jnp.uint32)
     got = ops.spray_select(
         counters, prof.c, sa % (1 << ell), 3, ell=ell, method=1,
-        backend="pallas",
+        backend="pallas", interpret=True,
     )
     want = ref.spray_select_ref(
         counters, prof.c, sa % (1 << ell), 3, ell=ell, method=1
@@ -64,7 +65,7 @@ def test_lt_encode_sweep(K, P, R, dmax):
     payload = jnp.asarray(RNG.integers(0, 2**32, (K, P), dtype=np.uint32))
     neigh = jnp.asarray(RNG.integers(0, K, (R, dmax), dtype=np.int32))
     valid = jnp.asarray(RNG.random((R, dmax)) < 0.7)
-    got = ops.lt_encode(payload, neigh, valid, backend="pallas")
+    got = ops.lt_encode(payload, neigh, valid, backend="pallas", interpret=True)
     want = ref.lt_encode_ref(payload, neigh, valid)
     assert np.array_equal(np.asarray(got), np.asarray(want))
 
@@ -73,7 +74,7 @@ def test_lt_encode_degree_one_is_copy():
     payload = jnp.asarray(RNG.integers(0, 2**32, (8, 512), dtype=np.uint32))
     neigh = jnp.asarray(np.arange(8, dtype=np.int32)[:, None])
     valid = jnp.ones((8, 1), bool)
-    got = ops.lt_encode(payload, neigh, valid, backend="pallas")
+    got = ops.lt_encode(payload, neigh, valid, backend="pallas", interpret=True)
     assert np.array_equal(np.asarray(got), np.asarray(payload))
 
 
@@ -96,7 +97,7 @@ def test_flash_attention_sweep(B, H, KVH, S, D, causal, window, dtype):
     v = jnp.asarray(RNG.standard_normal((B, KVH, S, D)), dtype)
     want = ref.flash_attention_ref(q, k, v, causal=causal, window=window)
     got = ops.flash_attention(
-        q, k, v, causal=causal, window=window, backend="pallas",
+        q, k, v, causal=causal, window=window, backend="pallas", interpret=True,
         block_q=128, block_k=128,
     )
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
@@ -121,7 +122,7 @@ def test_flash_attention_q_offset():
     k = jnp.asarray(RNG.standard_normal((B, H, S, D)), jnp.float32)
     v = jnp.asarray(RNG.standard_normal((B, H, S, D)), jnp.float32)
     got = ops.flash_attention(
-        q, k, v, causal=True, q_offset=64, backend="pallas",
+        q, k, v, causal=True, q_offset=64, backend="pallas", interpret=True,
         block_q=64, block_k=64,
     )
     want = ref.flash_attention_ref(q, k, v, causal=True, q_offset=64)
@@ -139,7 +140,9 @@ def test_flash_decode_sweep(B, H, KVH, S, D):
     k = jnp.asarray(RNG.standard_normal((B, S, KVH, D)), jnp.float32)
     v = jnp.asarray(RNG.standard_normal((B, S, KVH, D)), jnp.float32)
     kv_len = jnp.asarray(RNG.integers(1, S, B), jnp.int32)
-    got = ops.flash_decode(q, k, v, kv_len, backend="pallas", block_s=256)
+    got = ops.flash_decode(
+        q, k, v, kv_len, backend="pallas", block_s=256, interpret=True
+    )
     want = ref.flash_decode_ref(q, k, v, kv_len)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5
@@ -162,6 +165,7 @@ def test_lse_combine_equals_full():
             ops.flash_decode(
                 q, k[:, s * per : (s + 1) * per], v[:, s * per : (s + 1) * per],
                 lens, backend="pallas", block_s=128, return_lse=True,
+                interpret=True,
             )
         )
     got = ops.lse_combine(parts)

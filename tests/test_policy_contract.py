@@ -467,6 +467,8 @@ def test_seed_golden_file_keys_frozen():
     script never rewrites the seed file by default (content identity is
     pinned byte-for-byte by tests/test_sender_engine.py)."""
     seed_keys = set(np.load(os.path.join(GOLDEN_DIR, "transport_seed.npz")).keys())
+    assert set(GEN.META_KEYS) <= seed_keys
+    seed_keys -= set(GEN.META_KEYS)
     expected = {
         f"{pol.name}/{rel}/{field}"
         for pol in BASELINE_POLICIES

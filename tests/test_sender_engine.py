@@ -63,6 +63,17 @@ def test_simulate_message_matches_golden_trace(case):
         assert np.array_equal(got, want), (name, field, got, want)
 
 
+@pytest.mark.parametrize("fname", ["transport_seed.npz", "transport_policies.npz"])
+def test_golden_pinned_under_running_jax(fname):
+    """The traces pin one JAX version's random bits: a different JAX or PRNG
+    mode must be reported as such, not as a semantic change."""
+    pins = np.load(os.path.join(GOLDEN_DIR, fname))
+    assert str(pins["meta/jax_version"]) == jax.__version__
+    assert bool(pins["meta/threefry_partitionable"]) == bool(
+        jax.config.jax_threefry_partitionable
+    )
+
+
 def test_simulate_flows_matches_golden_trace():
     topo, sched, cfg, n_packets, seed, horizon = GEN.golden_flows_case()
     r = simulate_flows(topo, sched, cfg, n_packets, jax.random.PRNGKey(seed), horizon)
