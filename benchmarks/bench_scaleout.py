@@ -7,27 +7,19 @@ planes x 2 cores: n = 4 distinct 4-hop paths per inter-pod flow) — at
 `common.compile_gate`, exactly the `bench_topology` idiom lifted to the
 3-tier fabric.
 
-Two scale-out diagnostics ride along in `meta.perf`:
-
-  * scaling rows — the SAME family through the flow-sharded engine
-    (`sender.shard_sweep_flows_scenarios`) over `flow_mesh(d)` for each d
-    of the 1/2/4/8 ladder that does not exceed the devices JAX sees, all
-    in this process (a chip belongs to one process, so a child could not
-    reach it).  Each row reports ticks/s plus a digest of its `cct`
-    tensor, and the bench FAILS if any digest differs from the unsharded
-    sweep's: the scaling curve and the bit-identity claim are checked by
-    the same run.  On the CPU the devices are forced host devices
-    (`run.py --devices N` under ``JAX_PLATFORMS=cpu``); they share the
-    host's cores, so read those rows as a partition-overhead (not
-    speedup) measurement (see docs/BENCHMARKS.md).
-
-  * a tick-component breakdown — standalone jitted micro-kernels of the
-    three hot tick components at the family's own shapes (scatter-ring
-    delivery + link scatter-adds; the lane path-assign `lax.switch`; the
-    per-flow PRNG split), timed with `common.timeit` and attached to the
-    family's perf row as normalized shares of *accounted component* time.
-    These compile outside `aot_compile` on purpose: they are diagnostics,
-    not family programs, and must not trip the compile gate.
+A scale-out diagnostic rides along in `meta.perf`: scaling rows — the
+SAME family through the flow-sharded engine
+(`sender.shard_sweep_flows_scenarios`) over `flow_mesh(d)` for each d of
+the 1/2/4/8 ladder that does not exceed the devices JAX sees, all in this
+process (a chip belongs to one process, so a child could not reach it).
+Each row reports ticks/s plus a digest of its `cct` tensor, and the bench
+FAILS if any digest differs from the unsharded sweep's: the scaling curve
+and the bit-identity claim are checked by the same run.  On the CPU the
+devices are forced host devices (`run.py --devices N` under
+``JAX_PLATFORMS=cpu``); they share the host's cores, so read those rows as
+a partition-overhead (not speedup) measurement (see docs/BENCHMARKS.md).
+Where the tick's device time goes is read from a chip trace by the
+benchmark in `bench/` (its `tick.*` metrics), not here.
 """
 from __future__ import annotations
 
@@ -36,7 +28,6 @@ import hashlib
 import os
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from benchmarks import common
@@ -46,7 +37,6 @@ from benchmarks.common import (
     compile_gate,
     emit,
     timed_call,
-    timeit,
 )
 from repro.net.scenarios import fat_tree_scenarios, stack_scenarios
 from repro.net.sender import (
@@ -108,68 +98,6 @@ def _digest(cct) -> str:
     ).tobytes()).hexdigest()[:16]
 
 
-def _tick_breakdown(topos, spec: SenderSpec) -> dict:
-    """Per-tick seconds of the three hot tick components, measured as
-    standalone jitted kernels at the family's [F, n] / [H, F, n] shapes
-    (first scenario's route).  Estimates for the perf-row breakdown — the
-    engine fuses these inside one scan, so shares are indicative, not an
-    in-situ profile."""
-    from repro.core.profile import uniform_profile
-    from repro.core.spray import SprayState
-    from repro.net.sender import assign_paths
-    from repro.net.topology import _link_sum, scatter_delivery
-
-    route = topos.route[0]                      # [H, F, n]
-    H, F, n = (int(d) for d in route.shape)
-    L = int(topos.capacity.shape[-1])
-    ring_len = topos.ring_len
-    k = jax.random.PRNGKey(0)
-    ka, kb, kc, kd = jax.random.split(k, 4)
-    arrive = jnp.zeros((F, ring_len), jnp.float32)
-    slot = jax.random.randint(ka, (F, n), 0, ring_len, jnp.int32)
-    exiting = jax.random.uniform(kb, (F, n), jnp.float32)
-    vals = jax.random.uniform(kc, (H, F, n), jnp.float32)
-
-    # scatter-ring: one delivery-ring deposit + the tick's two link
-    # scatter-adds (backlog + incoming) over the full [H, F, n] route
-    scatter_fn = jax.jit(lambda a, s, e, v: (
-        scatter_delivery(a, s, e), _link_sum(v, route, L),
-        _link_sum(v, route, L),
-    ))
-
-    # path-assign: every flow's rate_cap-lane lax.switch assignment (WAM
-    # branch is the hot one: spray_key + select_path per lane)
-    mask = jnp.uint32((1 << spec.ell) - 1)
-    prof = uniform_profile(n, spec.ell)
-
-    def one(j, sa, sb, kf):
-        spray = SprayState(
-            j=j, sa=sa & mask, sb=(sb & mask) | jnp.uint32(1),
-            path_seq=jnp.zeros((n,), jnp.int32),
-            ell=spec.ell, method=int(spec.method),
-        )
-        arrivals, _ = assign_paths(
-            spec.rate_cap, n, jnp.int32(int(Policy.WAM)), spray, prof,
-            jnp.int32(spec.rate_cap), kf, jnp.int32(0),
-        )
-        return arrivals
-
-    assign_fn = jax.jit(jax.vmap(one))
-    js = jnp.zeros((F,), jnp.uint32)
-    sas = jnp.arange(F, dtype=jnp.uint32)
-    sbs = jnp.arange(F, dtype=jnp.uint32) * 2 + 1
-    fkeys = jax.random.split(kd, F)
-
-    # rng: the per-tick per-flow key derivation
-    rng_fn = jax.jit(lambda kk: jax.random.split(kk, F))
-
-    return {
-        "scatter_ring": timeit(scatter_fn, arrive, slot, exiting, vals) / 1e6,
-        "path_assign": timeit(assign_fn, js, sas, sbs, fkeys) / 1e6,
-        "rng": timeit(rng_fn, k) / 1e6,
-    }
-
-
 def _scaling_row(topos, scheds, spec, sp, keys, sh: dict, n_devices: int
                  ) -> dict:
     """One scaling point: shard the family over `flow_mesh(n_devices)`,
@@ -213,22 +141,12 @@ def main() -> None:
     base_digest = _digest(r.cct)
     sims = ccts.size // F
 
-    breakdown = _tick_breakdown(topos, spec)
     common.perf(
         "scaleout_3tier_family",
         fabric_ticks=sims * horizon,
         path_decisions=float(np.asarray(r.sent_total).sum()),
         compile_s=compile_s,
         run_s=run_s,
-        breakdown=breakdown,
-    )
-    acct = sum(breakdown.values())
-    emit(
-        "scaleout/breakdown",
-        acct * 1e6,
-        ";".join(
-            f"{k}={v / acct:.2f}" for k, v in breakdown.items()
-        ) + f";per_tick_us={acct * 1e6:.1f}",
     )
 
     for si, scen_name in enumerate(scens):

@@ -375,7 +375,6 @@ def perf(
     run_s: float,
     nominal_decisions: bool = False,
     devices: int = 1,
-    breakdown: Dict[str, float] | None = None,
 ) -> None:
     """Record one meta.perf row: simulator throughput + wall split.
 
@@ -394,11 +393,7 @@ def perf(
     Every row is tagged with the device count its program ran on
     (`devices`: 1 for an unsharded program, the mesh size for a
     flow-sharded one) so single- and multi-device rows of the same family
-    are never conflated.  An
-    optional `breakdown` maps tick-component names (e.g. ``scatter_ring``,
-    ``path_assign``, ``rng``) to measured seconds; shares are normalized
-    over the components so the row reads as "fraction of accounted
-    component time", not of total wall (see `bench_scaleout`).
+    are never conflated.
     """
     total = compile_s + run_s
     row: Dict[str, object] = {
@@ -415,12 +410,6 @@ def perf(
         "run_s": round(run_s, 3),
         "run_frac": round(run_s / max(total, 1e-9), 3),
     }
-    if breakdown:
-        comp_total = max(sum(breakdown.values()), 1e-12)
-        row["breakdown"] = {
-            k: {"seconds": round(v, 6), "share": round(v / comp_total, 3)}
-            for k, v in breakdown.items()
-        }
     PERF_STATS.append(row)
 
 

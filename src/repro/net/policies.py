@@ -237,7 +237,16 @@ def policy_branches(
         # policies for real must enable the blocks (sender.spec_for_policies).
         return fn if block_width else rand_static
 
-    return [
+    def scoped(policy: Policy, fn):
+        # a branch's ops carry its policy's name in their HLO metadata
+        # (`tick/assign/wam`), which the benchmark's trace reduction reads
+        def branch():
+            with jax.named_scope(policy.name.lower()):
+                return fn()
+
+        return branch
+
+    return [scoped(p, fn) for p, fn in zip(Policy, (
         ecmp,
         rr,
         rand_static,
@@ -246,4 +255,4 @@ def policy_branches(
         gated(prime, pstate.entropy.shape[-1]),
         gated(strack, pstate.rtt.shape[-1] and pstate.penalty.shape[-1]),
         gated(cc_coupled, pstate.ccw.shape[-1]),
-    ]
+    ))]

@@ -425,9 +425,12 @@ def _scan_early_exit(spec, sender_tick, carry0, tkeys, horizon: int,
         carry, _ = jax.lax.scan(sender_tick, carry, ks)
         return (i + 1, carry)
 
-    _, carry = jax.lax.while_loop(cond, body, (jnp.int32(0), carry0))
-    if rem:
-        carry, _ = jax.lax.scan(sender_tick, carry, tkeys[n_full * chunk:])
+    with jax.named_scope("engine/chunk"):
+        _, carry = jax.lax.while_loop(cond, body, (jnp.int32(0), carry0))
+        if rem:
+            carry, _ = jax.lax.scan(
+                sender_tick, carry, tkeys[n_full * chunk:]
+            )
     return carry
 
 
@@ -501,95 +504,124 @@ def run_sender(
     need = completion_need(n_packets, spec.coded, sp.code_overhead)
     rate = jnp.minimum(sp.rate, spec.rate_cap)  # lanes are rate_cap wide
     adaptive = profile_adaptive(sp.policy)
-    tkeys = tick_keys(k_loop, horizon)
+    with jax.named_scope("engine/keys"):
+        tkeys = tick_keys(k_loop, horizon)
     pstate0 = init_policy_state(
         spec.state_blocks, lead, n, latency=latency_f, sa=spray0.sa
     )
 
     def sender_tick(carry, kt):
-        (
-            fabric, ctrl, spray, sent_sched, debt, done_at, sent_pp, known,
-            pstate,
-        ) = carry
-        t = fabric.t
-        ka, kb = kt[0], kt[1]
+        # Named scopes label the tick's ops in the compiled HLO's metadata
+        # (`tick/assign`, `tick/fabric`, `tick/sender`), which is how the
+        # benchmark's trace reduction attributes device time; they change
+        # no op.
+        with jax.named_scope("tick"):
+            with jax.named_scope("sender"):
+                (
+                    fabric, ctrl, spray, sent_sched, debt, done_at, sent_pp,
+                    known, pstate,
+                ) = carry
+                t = fabric.t
+                ka, kb = kt[0], kt[1]
 
-        # --- emit budget ---
-        if spec.coded:
-            # keep the pipe full until completion
-            k_emit = jnp.where(done_at >= 0, 0, rate).astype(jnp.int32)
-        else:
-            outstanding = jnp.maximum(n_packets - sent_sched, 0.0) + debt
-            known_delivered, known_dropped = known
-            in_flight = (
-                jnp.sum(sent_pp, axis=-1) - known_delivered - known_dropped
-            )
-            room = jnp.maximum(sp.cwnd - in_flight, 0.0)
-            # ceil: the fabric is a fluid model (fractional service during
-            # degradation), but the sender emits whole packets — rounding debt
-            # down would strand a fractional residue short of completion.
-            k_emit = jnp.ceil(
-                jnp.minimum(
-                    jnp.minimum(outstanding, room), rate.astype(jnp.float32)
+                # --- emit budget ---
+                if spec.coded:
+                    # keep the pipe full until completion
+                    k_emit = jnp.where(done_at >= 0, 0, rate).astype(
+                        jnp.int32
+                    )
+                else:
+                    outstanding = (
+                        jnp.maximum(n_packets - sent_sched, 0.0) + debt
+                    )
+                    known_delivered, known_dropped = known
+                    in_flight = (
+                        jnp.sum(sent_pp, axis=-1) - known_delivered
+                        - known_dropped
+                    )
+                    room = jnp.maximum(sp.cwnd - in_flight, 0.0)
+                    # ceil: the fabric is a fluid model (fractional service
+                    # during degradation), but the sender emits whole
+                    # packets — rounding debt down would strand a
+                    # fractional residue short of completion.
+                    k_emit = jnp.ceil(
+                        jnp.minimum(
+                            jnp.minimum(outstanding, room),
+                            rate.astype(jnp.float32),
+                        )
+                    ).astype(jnp.int32)
+
+            # --- spray / path assignment (traced-policy lax.switch) ---
+            with jax.named_scope("assign"):
+                arrivals, spray = assign_fn(
+                    spray, pstate, ctrl.profile, k_emit, ka, ecmp_path
                 )
-            ).astype(jnp.int32)
+            with jax.named_scope("sender"):
+                sent_pp = sent_pp + arrivals
+            with jax.named_scope("fabric"):
+                fabric, fb = stepper(fabric, arrivals, kb)
 
-        # --- spray / path assignment (traced-policy lax.switch) ---
-        arrivals, spray = assign_fn(
-            spray, pstate, ctrl.profile, k_emit, ka, ecmp_path
-        )
-        sent_pp = sent_pp + arrivals
-        fabric, fb = stepper(fabric, arrivals, kb)
+            with jax.named_scope("sender"):
+                # --- per-policy state blocks <- delayed per-path feedback
+                # Statically skipped when no block is enabled (the
+                # default), which is what keeps the stateless engine — and
+                # the goldens — untouched.  The update runs every tick
+                # (unlike the profile controller's cadence) and consumes NO
+                # PRNG; tick t's assignment above read the state as of tick
+                # t-1's feedback.
+                if spec.state_blocks:
+                    sent_m = jnp.maximum(fb["sent"], 1e-6)
+                    seen1 = jnp.minimum(fb["sent"], 1.0)
+                    pstate = update_policy_state(
+                        pstate,
+                        ecn_rate=fb["marked"] / sent_m * seen1,
+                        loss_rate=fb["dropped"] / sent_m * seen1,
+                        rtt_sample=latency_f + fb["qdelay"],
+                        seen=fb["sent"] > 0,
+                    )
 
-        # --- per-policy state blocks <- delayed per-path feedback ---
-        # Statically skipped when no block is enabled (the default), which
-        # is what keeps the stateless engine — and the goldens — untouched.
-        # The update runs every tick (unlike the profile controller's
-        # cadence) and consumes NO PRNG; tick t's assignment above read the
-        # state as of tick t-1's feedback.
-        if spec.state_blocks:
-            sent_m = jnp.maximum(fb["sent"], 1e-6)
-            seen1 = jnp.minimum(fb["sent"], 1.0)
-            pstate = update_policy_state(
-                pstate,
-                ecn_rate=fb["marked"] / sent_m * seen1,
-                loss_rate=fb["dropped"] / sent_m * seen1,
-                rtt_sample=latency_f + fb["qdelay"],
-                seen=fb["sent"] > 0,
-            )
+                # --- retransmission debt (uncoded): NACKed drops re-enter
+                # the stream
+                new_debt = debt + jnp.sum(fb["dropped"], axis=-1) - (
+                    jnp.maximum(
+                        k_emit - jnp.maximum(n_packets - sent_sched, 0.0),
+                        0.0,
+                    )
+                )
+                new_debt = jnp.maximum(new_debt, 0.0)
+                sent_sched = sent_sched + k_emit
 
-        # --- retransmission debt (uncoded): NACKed drops re-enter the stream
-        new_debt = debt + jnp.sum(fb["dropped"], axis=-1) - (
-            jnp.maximum(k_emit - jnp.maximum(n_packets - sent_sched, 0.0), 0.0)
-        )
-        new_debt = jnp.maximum(new_debt, 0.0)
-        sent_sched = sent_sched + k_emit
+                # --- delayed feedback -> profile controller (adaptive
+                # policies)
+                def do_ctrl(c):
+                    sent = jnp.maximum(fb["sent"], 1e-6)
+                    stats = PathStats(
+                        ecn_rate=fb["marked"] / sent
+                        * jnp.minimum(fb["sent"], 1.0),
+                        loss_rate=fb["dropped"] / sent
+                        * jnp.minimum(fb["sent"], 1.0),
+                        rtt=latency_f + fb["qdelay"],
+                    )
+                    return ctrl_update(c, stats)
 
-        # --- delayed feedback -> profile controller (adaptive policies) ---
-        def do_ctrl(c):
-            sent = jnp.maximum(fb["sent"], 1e-6)
-            stats = PathStats(
-                ecn_rate=fb["marked"] / sent * jnp.minimum(fb["sent"], 1.0),
-                loss_rate=fb["dropped"] / sent * jnp.minimum(fb["sent"], 1.0),
-                rtt=latency_f + fb["qdelay"],
-            )
-            return ctrl_update(c, stats)
+                ctrl = jax.lax.cond(
+                    adaptive & ((t % sp.ctrl_interval) == 0),
+                    do_ctrl, lambda c: c, ctrl,
+                )
 
-        ctrl = jax.lax.cond(
-            adaptive & ((t % sp.ctrl_interval) == 0), do_ctrl, lambda c: c, ctrl
-        )
-
-        # --- completion detection ---
-        known = (
-            known[0] + fb["landed"],
-            known[1] + jnp.sum(fb["dropped"], axis=-1),
-        )
-        done_now = (received_fn(fabric) >= need) & (done_at < 0)
-        done_at = jnp.where(done_now, t.astype(jnp.int32) + 1, done_at)
-        return (
-            fabric, ctrl, spray, sent_sched, new_debt, done_at, sent_pp,
-            known, pstate,
-        ), None
+                # --- completion detection ---
+                known = (
+                    known[0] + fb["landed"],
+                    known[1] + jnp.sum(fb["dropped"], axis=-1),
+                )
+                done_now = (received_fn(fabric) >= need) & (done_at < 0)
+                done_at = jnp.where(
+                    done_now, t.astype(jnp.int32) + 1, done_at
+                )
+                return (
+                    fabric, ctrl, spray, sent_sched, new_debt, done_at,
+                    sent_pp, known, pstate,
+                ), None
 
     zeros = jnp.zeros(lead, jnp.float32)
     # empty messages (need <= 0) complete at tick 0, not the horizon sentinel
@@ -607,10 +639,11 @@ def run_sender(
         (zeros, zeros),
         pstate0,
     )
-    if settle_reduce is None:
-        settled_fn = lambda c: _settled(spec, c)  # noqa: E731
-    else:
-        settled_fn = lambda c: settle_reduce(_settled(spec, c))  # noqa: E731
+    def settled_fn(c):
+        with jax.named_scope("engine/settle"):
+            done = _settled(spec, c)
+            return done if settle_reduce is None else settle_reduce(done)
+
     tspec = spec.telemetry
     if tspec is None:
         if spec.early_exit:
@@ -672,22 +705,25 @@ def run_sender(
             )
         else:
             (carry, frame), _ = jax.lax.scan(tel_tick, (carry0, tel0), tkeys)
-    (fabric, ctrl, _, _, _, done_at, sent_pp, _, _) = carry
-    cct = jnp.where(done_at >= 0, done_at.astype(jnp.float32), float(horizon))
-    if link_fn is not None:
-        link_served, link_busy = link_fn(fabric)
-    else:
-        link_served = link_busy = jnp.zeros((0,), jnp.float32)
-    result = SimResult(
-        cct=cct,
-        sent_total=sent_pp,
-        dropped_total=dropped_fn(fabric),
-        final_b=ctrl.profile.b,
-        received=received_fn(fabric),
-        finished=done_at >= 0,
-        link_served=link_served,
-        link_busy=link_busy,
-    )
+    with jax.named_scope("engine/result"):
+        (fabric, ctrl, _, _, _, done_at, sent_pp, _, _) = carry
+        cct = jnp.where(
+            done_at >= 0, done_at.astype(jnp.float32), float(horizon)
+        )
+        if link_fn is not None:
+            link_served, link_busy = link_fn(fabric)
+        else:
+            link_served = link_busy = jnp.zeros((0,), jnp.float32)
+        result = SimResult(
+            cct=cct,
+            sent_total=sent_pp,
+            dropped_total=dropped_fn(fabric),
+            final_b=ctrl.profile.b,
+            received=received_fn(fabric),
+            finished=done_at >= 0,
+            link_served=link_served,
+            link_busy=link_busy,
+        )
     return result if frame is None else (result, frame)
 
 

@@ -527,69 +527,82 @@ def shared_fabric_tick(
     q_in = state.queue + inflow            # [H, F, n]
     bg_q = state.bg_queue + bg_in          # [L]
 
-    # --- shared tail-drop: charge incoming traffic proportionally ---
-    backlog = flow_sum(q_in) + bg_q                     # [L]
-    incoming = flow_sum(inflow) + bg_in                 # [L]
-    dropable = jnp.minimum(
-        jnp.maximum(backlog - topo.queue_limit, 0.0), incoming
-    )
-    drop_frac = jnp.where(incoming > 0, dropable / jnp.maximum(incoming, 1e-9), 0.0)
-    drops = inflow * drop_frac[route]                   # [H, F, n]
-    bg_drop = bg_in * drop_frac
-    q_in = q_in - drops
-    bg_q = bg_q - bg_drop
-    backlog = backlog - dropable
+    # The named scopes below label these ops in the compiled HLO's metadata
+    # (`tick/fabric/link_sums`, ...), for the benchmark's trace reduction.
+    with jax.named_scope("link_sums"):
+        # --- shared tail-drop: charge incoming traffic proportionally ---
+        backlog = flow_sum(q_in) + bg_q                     # [L]
+        incoming = flow_sum(inflow) + bg_in                 # [L]
+        dropable = jnp.minimum(
+            jnp.maximum(backlog - topo.queue_limit, 0.0), incoming
+        )
+        drop_frac = jnp.where(
+            incoming > 0, dropable / jnp.maximum(incoming, 1e-9), 0.0
+        )
+        drops = inflow * drop_frac[route]                   # [H, F, n]
+        bg_drop = bg_in * drop_frac
+        q_in = q_in - drops
+        bg_q = bg_q - bg_drop
+        backlog = backlog - dropable
 
-    # --- fluid FIFO service: share capacity in proportion to backlog ---
-    served_l = jnp.minimum(backlog, cap)
-    serve_frac = jnp.where(
-        backlog > 0, served_l / jnp.maximum(backlog, 1e-9), 0.0
-    )
-    served = q_in * serve_frac[route]                   # [H, F, n]
-    bg_out = bg_q * serve_frac
-    queue = q_in - served
-    bg_queue = bg_q - bg_out
-    residual = backlog - served_l                       # [L]
+        # --- fluid FIFO service: share capacity in proportion to backlog ---
+        served_l = jnp.minimum(backlog, cap)
+        serve_frac = jnp.where(
+            backlog > 0, served_l / jnp.maximum(backlog, 1e-9), 0.0
+        )
+        served = q_in * serve_frac[route]                   # [H, F, n]
+        bg_out = bg_q * serve_frac
+        queue = q_in - served
+        bg_queue = bg_q - bg_out
+        residual = backlog - served_l                       # [L]
 
-    # --- per-path signals accumulated along the hops ---
-    qdelay_l = jnp.where(cap > 0, residual / jnp.maximum(cap, 1e-6), 0.0)
-    path_qdelay = jnp.sum(qdelay_l[route], axis=0)      # [F, n]
-    path_drops = jnp.sum(drops, axis=0)                 # [F, n]
-    over = residual > topo.ecn_threshold                # [L]
-    path_marked = jnp.any(over[route], axis=0)          # [F, n]
-    exiting = served[-1]                                # [F, n] leave last hop
-    marked = jnp.where(path_marked, exiting, 0.0)
+    with jax.named_scope("signals"):
+        # --- per-path signals accumulated along the hops ---
+        qdelay_l = jnp.where(cap > 0, residual / jnp.maximum(cap, 1e-6), 0.0)
+        path_qdelay = jnp.sum(qdelay_l[route], axis=0)      # [F, n]
+        path_drops = jnp.sum(drops, axis=0)                 # [F, n]
+        over = residual > topo.ecn_threshold                # [L]
+        path_marked = jnp.any(over[route], axis=0)          # [F, n]
+        exiting = served[-1]                                # [F, n] last hop
+        marked = jnp.where(path_marked, exiting, 0.0)
 
-    # --- schedule deliveries: propagation + rounded queueing delay ---
-    delay = topo.latency + jnp.round(path_qdelay).astype(jnp.int32)
-    delay = jnp.minimum(delay, topo.ring_len - 1)
-    slot = (t + 1 + delay) % topo.ring_len              # [F, n]
-    arrive_ring = scatter_delivery(state.arrive_ring, slot, exiting)
-    cur = t % topo.ring_len
-    landed = arrive_ring[:, cur]
-    arrive_ring = arrive_ring.at[:, cur].set(0.0)
-    received = state.received + landed
+    with jax.named_scope("delivery"):
+        # --- schedule deliveries: propagation + rounded queueing delay ---
+        delay = topo.latency + jnp.round(path_qdelay).astype(jnp.int32)
+        delay = jnp.minimum(delay, topo.ring_len - 1)
+        slot = (t + 1 + delay) % topo.ring_len              # [F, n]
+        arrive_ring = scatter_delivery(state.arrive_ring, slot, exiting)
+        cur = t % topo.ring_len
+        landed = arrive_ring[:, cur]
+        arrive_ring = arrive_ring.at[:, cur].set(0.0)
+        received = state.received + landed
 
-    # --- delayed feedback rings (per flow, fabric_tick contract) ---
-    fbwin = topo.fb_delay
-    w = t % fbwin
-    fb = dict(
-        sent=state.sent_ring[:, w, :],
-        marked=state.mark_ring[:, w, :],
-        dropped=state.drop_ring[:, w, :],
-        qdelay=state.qdelay_ring[:, w, :],
-        landed=landed,
-    )
+    with jax.named_scope("feedback"):
+        # --- delayed feedback rings (per flow, fabric_tick contract) ---
+        fbwin = topo.fb_delay
+        w = t % fbwin
+        fb = dict(
+            sent=state.sent_ring[:, w, :],
+            marked=state.mark_ring[:, w, :],
+            dropped=state.drop_ring[:, w, :],
+            qdelay=state.qdelay_ring[:, w, :],
+            landed=landed,
+        )
+    forward = served[:-1]
+    with jax.named_scope("feedback"):
+        rings = dict(
+            sent_ring=state.sent_ring.at[:, w, :].set(arrivals),
+            mark_ring=state.mark_ring.at[:, w, :].set(marked),
+            drop_ring=state.drop_ring.at[:, w, :].set(path_drops),
+            qdelay_ring=state.qdelay_ring.at[:, w, :].set(path_qdelay),
+        )
     new_state = SharedFabricState(
         queue=queue,
-        forward=served[:-1],
+        forward=forward,
         bg_queue=bg_queue,
         degraded=degraded,
         arrive_ring=arrive_ring,
-        sent_ring=state.sent_ring.at[:, w, :].set(arrivals),
-        mark_ring=state.mark_ring.at[:, w, :].set(marked),
-        drop_ring=state.drop_ring.at[:, w, :].set(path_drops),
-        qdelay_ring=state.qdelay_ring.at[:, w, :].set(path_qdelay),
+        **rings,
         received=received,
         dropped=state.dropped + path_drops,
         bg_served=state.bg_served + bg_out,
