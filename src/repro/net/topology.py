@@ -546,9 +546,16 @@ def shared_fabric_tick(
         backlog = backlog - dropable
 
         # --- fluid FIFO service: share capacity in proportion to backlog ---
+        # A link that can serve its whole backlog serves it all: the
+        # fraction is exactly 1, so its queues drain to exact zeros (what
+        # `fabric_quiescent` tests) and the flows are served what the link
+        # counter books.  Only an over-capacity link serves a fraction.
         served_l = jnp.minimum(backlog, cap)
+        short = backlog > cap
         serve_frac = jnp.where(
-            backlog > 0, served_l / jnp.maximum(backlog, 1e-9), 0.0
+            short,
+            served_l / jnp.where(short, backlog, 1.0),
+            (backlog > 0).astype(jnp.float32),
         )
         served = q_in * serve_frac[route]                   # [H, F, n]
         bg_out = bg_q * serve_frac
