@@ -7,7 +7,9 @@ configuration (`bench/configs/<config>.json`), its traffic mix
 (`bench/traffic/<traffic>.json`, whose `kind` names a generator,
 `bench/generators/<kind>.py`) and its chips; `bench/cells/<cell>.json` holds the
 cell's correctness limits and how many calls the check and the trace take;
-each per-layer metric is read by `bench/metrics/<metric>.py`.  A new cell,
+each per-layer metric is read by `bench/metrics/<metric>.py` from the trace
+and the named scopes of the programs it ran (a `bench.scopes.ScopedTrace`:
+`trace.shares()`, `trace.scope_seconds("tick/assign")`).  A new cell,
 configuration, mix, generator or metric is new files, and no edit here.
 
 A run has three parts:
@@ -39,6 +41,7 @@ import time
 import numpy as np
 
 from bench import common, generators, trace as tracing
+from bench.scopes import ScopedTrace, op_scopes
 
 CACHE_DIR = ".bench_cache/jax"   # inside the checkout; fixed, never moved
 
@@ -189,6 +192,7 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
                     f.write(text)
         tr = tracing.load(trace_dir, n_devices=cell.chips,
                           remove=keep_trace is None, programs=programs)
+        tr = ScopedTrace.of(tr, op_scopes(programs) if programs else None)
         device["busy_s"] = tr.busy_s()
         device["window_s"] = tr.window_s()
         for m in cell.per_layer:

@@ -11,6 +11,8 @@ early-exit chunk scans), `engine/settle` and `engine/result` round it.
 `op_scopes` reads them, and how many times each `while` loops;
 `ScopedTrace` is a `bench.trace.Trace` that carries them, and reads op time
 by component (`shares`) and the ticks a traced call ran (`ticks_per_call`).
+The harness hands every per-layer reader the trace of a `--trace 1` run as
+a `ScopedTrace` with the scopes of the programs it ran.
 
 A run with `--trace 1 --keep-trace DIR` keeps the raw trace and the traced
 programs' HLO in DIR; this reads them:

@@ -6,12 +6,19 @@ cell: new files under `bench/` and new entries in `BENCHMARK.json`.
 """
 from __future__ import annotations
 
+import glob
+import importlib.util
 import json
 import os
 import shutil
 import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+# the per-layer metrics that read the engine's named scopes: the four
+# shares that partition the op time, then the ticks a call ran
+SCOPE_METRICS = ("tick.assign_share", "tick.fabric_share", "tick.sender_share",
+                 "engine.outside_tick_share", "engine.ticks_per_call")
 
 TINY_FATTREE = {
     "source": "k=4-style fat-tree at test size", "fabric": "fat_tree",
@@ -85,3 +92,42 @@ def run(root, cell, *, seed=7, seconds=0.1, trace=False):
     assert json.loads(out.getvalue().splitlines()[-1]) == json.loads(
         json.dumps(result))
     return result
+
+
+def reader(name):
+    """The `read` function of the per-layer metric `name`'s reader."""
+    path = os.path.join(ROOT, "bench", "metrics", name + ".py")
+    spec = importlib.util.spec_from_file_location(name.replace(".", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+def cpu_ops_as_device(monkeypatch):
+    """Make the harness's traces on the CPU hold a device.  The CPU runs a
+    program's ops on host threads (`tf_XLA...` lines of `/host:CPU`), which
+    `Trace.from_profile` counts as no device; this lays every event there
+    that names an instruction of the traced programs on one stand-in device,
+    on the trace's own clock, so that the per-layer readers have ops and
+    loop runs to read."""
+    import jax
+
+    from bench import trace as tracing
+
+    load = tracing.load
+
+    def with_cpu_ops(trace_dir, n_devices=None, remove=True, programs=()):
+        path, = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                          recursive=True)
+        profile = jax.profiler.ProfileData.from_file(path)
+        tr = load(trace_dir, n_devices=n_devices, remove=remove,
+                  programs=programs)
+        names = tracing.op_kinds(programs)
+        tr.ops = {"/device:CPU:0": [
+            (e.name, e.start_ns * 1e-9, e.duration_ns * 1e-9)
+            for plane in profile.planes if plane.name == "/host:CPU"
+            for line in plane.lines if line.name.startswith("tf_XLA")
+            for e in line.events if e.name in names]}
+        return tr
+
+    monkeypatch.setattr(tracing, "load", with_cpu_ops)

@@ -13,14 +13,15 @@ import pytest
 
 from bench import generators
 from bench.harness import Cell
-from bench.tests.helpers import ROOT, TINY_FATTREE, TINY_PERMUTATION, run, tiny_root
+from bench.tests.helpers import (ROOT, SCOPE_METRICS, TINY_FATTREE,
+                                 TINY_PERMUTATION, cpu_ops_as_device, run,
+                                 tiny_root)
 
 NEW_METRIC = '''
 def read(trace):
     """Milliseconds of the traced window: found by its file name alone."""
     return 1000.0 * trace.window_s() if trace.spans else None
 '''
-
 
 def test_new_cell_traffic_and_metric_are_found_by_name(tmp_path):
     root = tiny_root(tmp_path, extra_metrics={"test.window_ms": NEW_METRIC})
@@ -31,10 +32,30 @@ def test_new_cell_traffic_and_metric_are_found_by_name(tmp_path):
     assert result["metrics"]["test.window_ms"]["value"] > 0
     assert result["metrics"]["test.window_ms"]["unit"] == "ms"
     assert "mesh.collective_share" not in result["metrics"]
+    # the CPU traces no device, so no op is found under a scope
+    assert not set(SCOPE_METRICS) & set(result["metrics"])
     assert list(result)[-1] == "checks"
     untraced = run(root, "tiny.permutation")
     assert set(untraced["metrics"]) == {"points_per_s", "setup_s"}
     assert untraced["metrics"]["points_per_s"]["value"] > 0
+
+
+def test_traced_run_reads_the_engine_scopes(tmp_path, monkeypatch):
+    """Every reader is handed the trace with the named scopes of the
+    programs it ran: with the CPU's ops laid on a device, a traced run of
+    the tiny cell reports the scope metrics, the four shares partition the
+    op time, and the chunk loops' runs give the ticks a call ran, within
+    the mix's horizon."""
+    cpu_ops_as_device(monkeypatch)
+    result = run(tiny_root(tmp_path), "tiny.permutation", trace=True)
+    assert result["correct"], result["checks"]
+    metrics = {k: v["value"] for k, v in result["metrics"].items()}
+    assert set(SCOPE_METRICS) <= set(metrics)
+    shares = [metrics[k] for k in SCOPE_METRICS[:4]]
+    assert sum(shares) == pytest.approx(100.0, abs=1e-9)
+    assert min(shares) >= 0 and metrics["tick.assign_share"] > 0
+    assert 0 < metrics["engine.ticks_per_call"] <= TINY_PERMUTATION["horizon"]
+    assert result["metrics"]["engine.ticks_per_call"]["unit"] == "ticks"
 
 
 # A generator of a new kind, added as a file: every host sends to the host

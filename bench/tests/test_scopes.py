@@ -8,7 +8,7 @@ import os
 import pytest
 
 from bench.scopes import OUTSIDE, SHARES, ScopedTrace, op_scopes, read, scope_path
-from bench.tests.helpers import ROOT
+from bench.tests.helpers import ROOT, SCOPE_METRICS, reader
 from bench.trace import op_kinds, save
 
 SCOPE = "jit(sweep)/vmap(vmap(engine/chunk))/while/body/while/body/closed_call"
@@ -335,3 +335,51 @@ def test_a_kept_run_is_read_with_its_programs_scopes(tmp_path, capsys):
     assert read(saved).scopes == tr.condensed().scopes
     save(tr, saved)     # what `bench.trace` saves of a scoped trace
     assert read(saved).scopes == tr.scopes
+
+
+PLAIN_CHIP_TRACE = os.path.join(ROOT, "bench", "testdata",
+                                "ft8.permutation.trace.json.gz")
+
+
+@pytest.mark.parametrize("name", SCOPE_METRICS)
+def test_scope_readers_read_the_recorded_chip_trace(name):
+    tr = read(SCOPED_CHIP_TRACE)
+    want = (tr.ticks_per_call() if name == "engine.ticks_per_call"
+            else tr.shares()[name])
+    assert reader(name)(tr) == want
+
+
+def test_scope_share_readers_partition_the_op_time():
+    tr = read(SCOPED_CHIP_TRACE)
+    assert SCOPE_METRICS[:4] == (*SHARES, OUTSIDE)
+    shares = [reader(name)(tr) for name in SCOPE_METRICS[:4]]
+    assert sum(shares) == pytest.approx(100.0, abs=1e-9)
+    assert min(shares) > 0
+
+
+@pytest.mark.parametrize("name", SCOPE_METRICS)
+def test_scope_readers_find_nothing_without_scopes(name):
+    # a recording from before the traced programs' HLO was kept
+    unscoped = read(PLAIN_CHIP_TRACE)
+    assert unscoped.scopes is None and reader(name)(unscoped) is None
+    # scopes in which no op lies under `tick` or `engine/chunk`
+    plain = scoped()
+    plain.scopes = {"ops": {n: "jit(sweep)/while/body/add" for n in plain.scopes["ops"]},
+                    "trips": {"while.c": 64}}
+    assert reader(name)(plain) is None
+
+
+@pytest.mark.parametrize("recording", [PLAIN_CHIP_TRACE, SCOPED_CHIP_TRACE])
+@pytest.mark.parametrize("name", ["device.idle_share", "tick.gather_share",
+                                  "tick.scatter_share", "mesh.collective_share"])
+def test_kind_readers_read_the_same_with_the_scopes(name, recording):
+    """What the harness hands the readers now, the trace with its scopes,
+    reads as the plain trace read for the readers by kind and for the
+    breakdown."""
+    from bench.trace import read as read_plain
+
+    plain, with_scopes = read_plain(recording), read(recording)
+    assert isinstance(with_scopes, ScopedTrace)
+    assert reader(name)(with_scopes) == reader(name)(plain)
+    assert with_scopes.breakdown() == plain.breakdown()
+    assert (with_scopes.busy_s(), with_scopes.window_s()) == (plain.busy_s(), plain.window_s())

@@ -1,21 +1,12 @@
 """The reduction from a trace to the per-layer metrics and the breakdown."""
 from __future__ import annotations
 
-import importlib.util
 import os
 
 import pytest
 
 from bench.trace import Trace, op_kinds, read
-from bench.tests.helpers import ROOT
-
-
-def reader(name):
-    path = os.path.join(ROOT, "bench", "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+from bench.tests.helpers import ROOT, reader
 
 
 def synthetic():
